@@ -49,6 +49,7 @@ from repro.core.fleet import FleetSim, MemberSpec
 from repro.core.runtime import BWRaftSim
 from repro.market import (HazardAwareBid, MarketTrace, kill_nodes, load,
                           mass_kill, run_chaos, warning_then_reprieve)
+from repro import compile_cache
 
 GOLDEN = pathlib.Path(__file__).parent.parent / "tests" / "data" / \
     "closed_loop_golden.json"
@@ -214,6 +215,7 @@ def retention_block(epochs: int) -> dict:
 
 
 def main(argv=None) -> int:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small sweep grid for CI (gates still apply)")

@@ -49,6 +49,7 @@ from repro.core import fleet as fleet_mod
 from repro.core import multiraft
 from repro.core.fleet import FleetSim
 from repro.core.state import pytree_nbytes
+from repro import compile_cache
 
 # hard ceilings enforced on the digest pipeline (CI regression gates):
 # per-member per-epoch device->host bytes must stay O(digest) — the
@@ -154,6 +155,7 @@ def measure_multiraft(systems: int, shards: int, epochs: int) -> dict:
 
 
 def main(argv=None) -> int:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small grid for CI (ceiling checks only, no "

@@ -47,6 +47,7 @@ from repro.core.runtime import BWRaftSim
 from repro.market import (calibrate_predictor, export_walk_trace, fit_walk,
                           load)
 from benchmarks.common import run_systems
+from repro import compile_cache
 
 # trace replay swaps one (S,) RNG-normal draw for one (S,) dynamic-slice
 # gather per tick — it must stay within this factor of the walk
@@ -158,6 +159,7 @@ def calibration_block() -> dict:
 
 
 def main(argv=None) -> int:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small grid for CI (no overhead-ceiling gate)")

@@ -50,6 +50,7 @@ from repro.configs.bwraft_kv import CONFIG
 from repro.core import fleet as fleet_mod
 from repro.core.fleet import FleetSim, MemberSpec
 from repro.core.runtime import BWRaftSim
+from repro import compile_cache
 
 # same digest ceiling perf_fleet.py / perf_serving.py enforce (§7.1)
 D2H_CEILING_BYTES_PER_MEMBER_EPOCH = 4096
@@ -169,6 +170,7 @@ def measure_mixed_sweep(widths, epochs: int) -> dict:
 
 
 def main(argv=None) -> int:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small grid for CI")

@@ -50,6 +50,7 @@ from repro.core.runtime import BWRaftSim, goodput_under_deadline
 from repro.workload import (ConstantRate, DiurnalRate, FlashCrowd, OpenLoop,
                             ZipfianKeys)
 from benchmarks.common import system_specs, tick_ms
+from repro import compile_cache
 
 # the serving SLO: a request is good if it completes within this many
 # ticks (1 tick = 10 ms — a 300 ms deadline, see `common.tick_ms`)
@@ -202,6 +203,7 @@ def serving_comparison(epochs: int, *, write_rate: float = 16.0,
 
 
 def main(argv=None) -> int:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small grid for CI")

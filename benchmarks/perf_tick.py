@@ -22,7 +22,7 @@ FAILS (exit 1) if any output or state leaf diverges, so CI catches
 kernel-contract regressions even on machines where the timings
 themselves are noise.
 
-Emits ``BENCH_tick.json``.  Interpret-mode caveat: off-TPU the pallas
+Emits ``BENCH_tick.json``.  Interpret-mode caveat: on CPU the pallas
 numbers measure the Pallas *interpreter* traced into XLA, not kernel
 speed (DESIGN.md §8).  Every timing block therefore carries an
 explicit ``"interpreted": true/false`` field — when it is true the
@@ -56,6 +56,7 @@ from repro.kernels.leader_fanout import ops as lf_ops
 from repro.kernels.leader_fanout import ref as lf_ref
 from repro.kernels.raft_tick import ops as rt_ops
 from repro.kernels.raft_tick import ref as rt_ref
+from repro import compile_cache
 
 SMOKE_CONFIG = ClusterConfig(
     name="bwraft-kv-smoke",
@@ -269,6 +270,7 @@ def bench_tick(cfg: ClusterConfig, static, T: int, iters: int):
 
 
 def main(argv=None) -> int:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small cluster + few iters for CI (equivalence "

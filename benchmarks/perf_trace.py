@@ -48,6 +48,7 @@ from repro.configs.bwraft_kv import CONFIG
 from repro.core.runtime import BWRaftSim
 from repro.market import kill_nodes, run_chaos
 from repro.trace import ring as trace_ring
+from repro import compile_cache
 
 # same digest ceiling perf_fleet.py / perf_market.py enforce (§7.1)
 D2H_CEILING_BYTES_PER_MEMBER_EPOCH = 4096
@@ -150,6 +151,7 @@ def drill_block(artifact: str) -> dict:
 
 
 def main(argv=None) -> int:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="fewer overhead reps for CI (gates still apply)")

@@ -5,8 +5,9 @@ Prints ``name,us_per_call,derived`` CSV.  Consensus benchmarks run inline
 batched fleet simulator (`core/fleet.FleetSim`): every (system, load)
 point in a figure is one member of a single vmapped program, so a grid
 costs one jit compile instead of one per point (DESIGN.md §7).  The
-roofline/dry-run benchmarks need 512 host devices and run as subprocesses
-(their results are also cached under results/).
+roofline benchmark needs 512 virtual CPU devices and runs as a child
+process with `JAX_PLATFORMS=cpu`: it only compiles, and the parent may
+hold the accelerator (its results are also cached under results/).
 
   PYTHONPATH=src python -m benchmarks.run [--full] [--sequential]
                                           [--with-roofline] [--only NAME]
@@ -18,6 +19,7 @@ A/B-ing the batched path or isolating a fleet regression.
 from __future__ import annotations
 
 import argparse
+import os
 import subprocess
 import sys
 import time
@@ -67,7 +69,10 @@ def main(argv=None) -> None:
         cmd = [sys.executable, "-m", "benchmarks.roofline",
                "--arch", "llama3.2-1b", "--shape", "decode_32k"]
         t0 = time.perf_counter()
-        subprocess.run(cmd, check=True)
+        # the parent has imported jax through the figure modules and may
+        # hold the chip; the child compiles for virtual CPU devices only
+        subprocess.run(cmd, check=True,
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"))
         rows.append(("roofline.llama_decode.wall",
                      (time.perf_counter() - t0) * 1e6, "us"))
 

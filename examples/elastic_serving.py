@@ -6,9 +6,11 @@ scaled online by the paper's Algorithm 1).
     PYTHONPATH=src python examples/elastic_serving.py
 """
 from repro.launch.serve import main as serve_main
+from repro import compile_cache
 
 
 def main():
+    compile_cache.enable()
     return serve_main(["--arch", "smollm-360m", "--requests", "48",
                        "--batch", "8", "--prompt-len", "32",
                        "--gen-len", "8", "--revoke-p", "0.15"])

@@ -7,9 +7,11 @@ the committed checkpoint record survives, observers keep serving reads.
 from repro.configs.bwraft_kv import CONFIG
 from repro.coord.coordinator import ConsensusCoordinator
 from repro.coord.elastic import ElasticObserverPool
+from repro import compile_cache
 
 
 def main():
+    compile_cache.enable()
     coord = ConsensusCoordinator(CONFIG, seed=1)
     lid = coord.wait_for_leader()
     print(f"leader: node {lid}")

@@ -13,9 +13,11 @@ from repro.configs.bwraft_kv import CONFIG
 from repro.core.runtime import BWRaftSim
 from repro.core import state as SM
 from repro.kvstore.service import BWKVService
+from repro import compile_cache
 
 
 def main():
+    compile_cache.enable()
     print("=== BW-Raft quickstart ===")
     sim = BWRaftSim(CONFIG, write_rate=2.0, read_rate=8.0, seed=0)
     svc = BWKVService(sim)
@@ -40,6 +42,11 @@ def main():
     sim.set_rates(phi=1.0)
     svc._step(5)
     sim.set_rates(phi=0.0)
+    spot = ~np.asarray(sim.static["is_voter"])
+    alive = np.asarray(sim.state["alive"])
+    assert not alive[spot].any() and alive[~spot].all()
+    print(f"phi=1: every spot node revoked, all {int((~spot).sum())} "
+          f"voters alive")
     r2 = svc.put("paper/venue", 42)
     v2, _ = svc.get("paper/venue")
     print(f"after revoking ALL spot instances: put/get still works -> {v2} "

@@ -27,9 +27,11 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 from benchmarks.common import scaled_cluster, run_systems
 from repro.market import HazardAwareBid, available_traces, load
+from repro import compile_cache
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--epochs", type=int, default=5)
     ap.add_argument("--trace", default=None, choices=available_traces(),

@@ -18,7 +18,7 @@ host-marshalling path and records it in BENCH_fleet.json.
 
 `--backend pallas` runs the same sweep through the Pallas kernel layer
 (raft_tick + leader fan-out + grouped digest reduction + anti-entropy
-sync; DESIGN.md §8; interpret mode off-TPU) — trajectories are
+sync; DESIGN.md §8; interpret mode on CPU) — trajectories are
 bit-identical, only execution differs; `benchmarks/perf_tick.py` is the
 measured comparison.  `--backend auto` (the library default) resolves
 per platform: pallas on TPU, xla everywhere else — the resolved choice
@@ -33,6 +33,7 @@ from repro.core.fleet import FleetSim
 from repro.core.runtime import BWRaftSim
 from repro.core.state import pytree_nbytes
 from repro.kernels import BACKENDS, resolve_backend
+from repro import compile_cache
 
 PHIS = [0.0, 0.01, 0.02, 0.05, 0.08, 0.1, 0.15, 0.2]
 WRITE_RATES = [4.0, 8.0, 16.0, 32.0]
@@ -40,6 +41,7 @@ EPOCHS = 3
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--backend", choices=BACKENDS, default="auto",
                     help="tick hot-op implementation (DESIGN.md §8); "

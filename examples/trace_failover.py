@@ -16,12 +16,14 @@ from collections import Counter
 from repro.configs.bwraft_kv import CONFIG
 from repro.market import kill_nodes, run_chaos
 from repro.trace import EVENT_NAMES, timeline
+from repro import compile_cache
 
 TICKS = 160
 KILL_TICK = 20
 
 
 def main():
+    compile_cache.enable()
     out = sys.argv[1] if len(sys.argv) > 1 else "trace_failover.json"
     faults = kill_nodes([0], KILL_TICK, n_nodes=CONFIG.max_nodes,
                         ticks=TICKS, name="leader-kill-traced")

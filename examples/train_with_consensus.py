@@ -8,11 +8,13 @@ hundred steps with BW-Raft-committed checkpoints, a simulated pod failure
 import shutil
 
 from repro.launch.train import main as train_main
+from repro import compile_cache
 
 CKPT = "/tmp/repro_example_ckpt"
 
 
 def main():
+    compile_cache.enable()
     shutil.rmtree(CKPT, ignore_errors=True)
     print("=== phase 1: train 200 steps, kill pod 1 at step 60 ===")
     train_main(["--arch", "llama3.2-1b", "--steps", "200",

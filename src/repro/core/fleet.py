@@ -191,8 +191,7 @@ _FLEET_EPOCH_CACHE: Dict = {}
 
 def total_compile_count() -> int:
     """Compiled batched-epoch programs across every fleet shape and
-    pipeline this process has run (robust to jax versions without the
-    private jit cache introspection — see `runtime.CountingJit`)."""
+    pipeline this process has run (`runtime.CountingJit`)."""
     return sum(fn.cache_size() for fn in _FLEET_EPOCH_CACHE.values())
 
 

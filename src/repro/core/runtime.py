@@ -50,32 +50,20 @@ from repro.workload import arrivals as workload_arrivals
 
 
 class CountingJit:
-    """`jax.jit` wrapper whose compile count survives jax upgrades.
-
-    Prefers the private `Wrapped._cache_size()` when the installed jax
-    still has it; otherwise falls back to counting distinct argument
-    signatures (treedef + leaf shapes/dtypes — the jit cache key modulo
-    weak types) observed at call time on this wrapper.  Used by every
-    cached epoch function so `FleetSim.compile_count` /
-    `fleet.total_compile_count` keep working across versions.
-    """
+    """`jax.jit` wrapper that reports how many programs it compiled,
+    read from the jit cache (`_cache_size()`, present in the pinned
+    jax).  Used by every cached epoch function, so
+    `FleetSim.compile_count` / `fleet.total_compile_count` count real
+    compiles."""
 
     def __init__(self, fun, **jit_kwargs):
         self.fn = jax.jit(fun, **jit_kwargs)
-        self._sigs = set()
 
     def __call__(self, *args):
-        leaves, treedef = jax.tree.flatten(args)
-        self._sigs.add((treedef,
-                        tuple((jnp.shape(x), jnp.result_type(x))
-                              for x in leaves)))
         return self.fn(*args)
 
     def cache_size(self) -> int:
-        try:
-            return int(self.fn._cache_size())
-        except Exception:
-            return len(self._sigs)
+        return int(self.fn._cache_size())
 
 
 # HIST_TAIL moved to `state.py` with the read-histogram state (§11); it

@@ -1,5 +1,5 @@
 """Public op for the anti-entropy sync kernel: padding, bitcast,
-dispatch, fallback.
+dispatch.
 
 `core/step.py:anti_entropy_step` calls `ae_sync` when
 `backend="pallas"` is resolved (DESIGN.md §8/§13).  The wrapper
@@ -12,8 +12,8 @@ dispatch, fallback.
     back on the way out (one-hot sums preserve the bit pattern),
   * flattens the (S, S) site-pair RTT matrix to a (1, S*S) row so the
     sync-hop gather is a single fused one-hot,
-  * compiles the Pallas kernel on TPU and falls back to
-    `interpret=True` everywhere else (the `raft_tick` fallback rule),
+  * compiles the Pallas kernel on TPU and interprets it on CPU (the
+    `raft_tick` interpret rule; any other platform raises),
   * slices the four dobs_* rows back to (O,).
 
 Bit-identical to `ref.py` and to the XLA formulation in

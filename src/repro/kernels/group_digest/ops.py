@@ -1,5 +1,5 @@
 """Public op for the grouped digest reduction: packing, padding,
-dispatch, fallback.
+dispatch.
 
 `core/fleet.py:_group_digest` calls `group_reduce` when
 `backend="pallas"` is resolved (DESIGN.md §8/§9).  The wrapper
@@ -11,8 +11,8 @@ dispatch, fallback.
   * pads B to a sublane multiple with dropped rows (segment id == G,
     the masking rule that also drops ungrouped members), F to lane
     multiples, and G to a sublane multiple,
-  * compiles the Pallas kernel on TPU and falls back to
-    `interpret=True` everywhere else (the `raft_tick` fallback rule),
+  * compiles the Pallas kernel on TPU and interprets it on CPU (the
+    `raft_tick` interpret rule; any other platform raises),
   * slices back to (G, ...) leaves.
 
 Bit-identical to `ref.py` (the segment-op formulation kept in

@@ -1,4 +1,4 @@
-"""Public op for the leader fan-out kernel: padding, dispatch, fallback.
+"""Public op for the leader fan-out kernel: padding, dispatch.
 
 `core/step.py:leader_step` calls `leader_fanout` when
 `backend="pallas"` is resolved (DESIGN.md §8).  The wrapper
@@ -7,8 +7,8 @@
     the RTT matrix to (Np, Np), Np a lane multiple — padded lanes carry
     `alive == 0`, which zeroes every ship/budget/rank contribution
     (masking contract; see kernel.py),
-  * compiles the Pallas kernel on TPU and falls back to
-    `interpret=True` everywhere else (the `raft_tick` fallback rule),
+  * compiles the Pallas kernel on TPU and interprets it on CPU (the
+    `raft_tick` interpret rule; any other platform raises),
   * slices the app_* rows back to (N,) and the work delta to a scalar.
 
 Bit-identical to `ref.py` and to the XLA formulation in

@@ -1,4 +1,4 @@
-"""Public ops for the raft_tick kernels: padding, dispatch, fallback.
+"""Public ops for the raft_tick kernels: padding, dispatch.
 
 The jitted wrappers below are what `core/step.py` calls when
 `backend="pallas"` is selected (DESIGN.md §8).  They
@@ -8,9 +8,9 @@ The jitted wrappers below are what `core/step.py` calls when
     arrive fully masked — `due`/`valid`/`voter_alive` pad with 0 — and
     padded columns are unreachable because window/commit bounds use the
     REAL sizes, passed statically),
-  * compile the Pallas kernel on TPU and fall back to `interpret=True`
-    everywhere else (the fallback rule), so the same tick runs — and
-    the tier-1 suite passes — on CPU-only hosts,
+  * compile the Pallas kernel on TPU and interpret it on CPU (the
+    interpret rule, `use_interpret`), so the same tick runs — and the
+    tier-1 suite passes — on CPU-only hosts; any other platform raises,
   * slice the result back to the caller's shapes.
 
 Each op is bit-identical to its `ref.py` twin and to the XLA
@@ -33,14 +33,19 @@ _BLOCK_LANE = 128   # lane width: L and K blocks
 
 
 def use_interpret() -> bool:
-    """interpret=True fallback rule: compile Pallas only on TPU;
-    everywhere else the kernels run through the Pallas interpreter —
-    inside jit, so they still trace into one XLA program (DESIGN.md §8).
-    GPU is deliberately interpret-only for now: the kernels lean on
-    TPU-specific pieces (pltpu VMEM/SMEM scratch, sequential grid
-    iteration carrying accumulators across L blocks) that the Triton
-    lowering does not honor — a Mosaic-GPU port is a ROADMAP item."""
-    return jax.default_backend() != "tpu"
+    """The interpret rule shared by all four kernel families: compile
+    the Pallas kernels on TPU, run them through the Pallas interpreter
+    on CPU (inside jit, so they still trace into one XLA program —
+    DESIGN.md §8), and refuse any other platform rather than hide it
+    behind the interpreter."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels compile for TPU and run interpreted on CPU; "
+        f"JAX's default backend is {platform!r}")
 
 
 def _pad_to(n: int, m: int) -> int:

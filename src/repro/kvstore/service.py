@@ -75,14 +75,19 @@ class BWKVService:
         return int(hashlib.sha1(key.encode()).hexdigest(), 16) % K
 
     def _step(self, n: int = 1) -> None:
+        """Advance the cluster `n` ticks on the sim's backend.  `cfg_c`
+        is a jit argument, so `sim.set_rates(...)` reaches the next
+        tick without a recompile."""
         import repro.core.step as step_mod
         if self._tickfn is None:
-            static, cfg_c = self.sim.static, self.sim.cfg_c
+            static, backend = self.sim.static, self.sim.backend
             self._tickfn = jax.jit(
-                lambda s, r: step_mod.tick(s, static, cfg_c, r))
+                lambda s, c, r: step_mod.tick(s, static, c, r,
+                                              backend=backend))
         for _ in range(n):
             self.sim.rng, sub = jax.random.split(self.sim.rng)
-            self.sim.state, _ = self._tickfn(self.sim.state, sub)
+            self.sim.state, _ = self._tickfn(self.sim.state,
+                                             self.sim.cfg_c, sub)
 
     def put(self, key: str, value: int) -> PutResult:
         """Submit a write through the leader; block until committed."""

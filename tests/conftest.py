@@ -48,3 +48,18 @@ def sim_trace_factory(paper_cluster):
         return trace, state
 
     return run
+
+
+@pytest.fixture(scope="session")
+def golden_note():
+    """Names the JAX version and PRNG setting a golden fixture was
+    recorded under next to the running ones, for failure messages: a
+    golden that diverges after a JAX upgrade says so."""
+    def note(golden):
+        meta = golden.get("_meta", {})
+        return (f"fixture recorded under jax {meta.get('jax_version')} "
+                f"(jax_threefry_partitionable="
+                f"{meta.get('jax_threefry_partitionable')}), running jax "
+                f"{jax.__version__} (jax_threefry_partitionable="
+                f"{jax.config.jax_threefry_partitionable})")
+    return note

@@ -206,3 +206,25 @@ def test_key_hash_pinned_values(svc):
                                        .hexdigest(), 16) % 1024
     assert svc._key_id("bwraft") == int(hashlib.sha1(b"bwraft")
                                         .hexdigest(), 16) % 1024
+
+
+def test_set_rates_reaches_the_service_tick():
+    """`set_rates` after the service has compiled its tick takes effect
+    on the next tick: phi = 1 revokes every spot node (leased dense
+    secretaries/observers and digest slots alike) within one tick, and
+    no voter (Property 3.4)."""
+    sim = BWRaftSim(CC, write_rate=0.0, read_rate=0.0, seed=9,
+                    manage_resources=False, n_observers=8)
+    s = BWKVService(sim)
+    s._step(120)
+    sim.lease_fixed(3, 4)
+    s._step(1)
+    spot = ~np.asarray(sim.static["is_voter"])
+    assert np.asarray(sim.state["alive"])[spot].sum() == 7
+    assert np.asarray(sim.state["dobs_alive"]).sum() == 8
+    sim.set_rates(phi=1.0)
+    s._step(1)
+    alive = np.asarray(sim.state["alive"])
+    assert not alive[spot].any()
+    assert not np.asarray(sim.state["dobs_alive"]).any()
+    assert alive[~spot].all()

@@ -28,7 +28,7 @@ from repro.workload import ConstantRate, DiurnalRate, FlashCrowd, OpenLoop
 GOLDEN = pathlib.Path(__file__).parent / "data" / "closed_loop_golden.json"
 
 
-def _check_golden(name, sim_state, reports, g):
+def _check_golden(name, sim_state, reports, g, note=""):
     """Reports: ints compare exactly, floats by repr round-trip; state
     leaves by sha256 over the raw bytes.  Only keys recorded in the
     golden are compared — fields/leaves ADDED by this PR (read
@@ -40,20 +40,23 @@ def _check_golden(name, sim_state, reports, g):
             got = getattr(rep, k)
             if isinstance(v, str):
                 assert repr(float(got)) == v, \
-                    f"{name} epoch {i}: {k} = {float(got)!r}, golden {v}"
+                    f"{name} epoch {i}: {k} = {float(got)!r}, golden {v}; " \
+                    f"{note}"
             else:
                 assert int(got) == v, \
-                    f"{name} epoch {i}: {k} = {int(got)}, golden {v}"
+                    f"{name} epoch {i}: {k} = {int(got)}, golden {v}; " \
+                    f"{note}"
     for k, leaf in g["state"].items():
         arr = np.asarray(sim_state[k])
         assert list(arr.shape) == leaf["shape"], (name, k)
         assert str(arr.dtype) == leaf["dtype"], (name, k)
         got = hashlib.sha256(arr.tobytes()).hexdigest()
         assert got == leaf["sha256"], \
-            f"{name}: state leaf {k!r} diverged from pre-PR trajectory"
+            f"{name}: state leaf {k!r} diverged from pre-PR trajectory; " \
+            f"{note}"
 
 
-def test_closed_loop_solo_bit_identical_to_golden():
+def test_closed_loop_solo_bit_identical_to_golden(golden_note):
     """A managed solo run (control plane + synthetic market on) replays
     the pre-PR trajectory exactly: the open-loop path is compiled in but
     `open_loop=False` selects the scalar knob, same lam -> same draws."""
@@ -61,10 +64,11 @@ def test_closed_loop_solo_bit_identical_to_golden():
     sim = BWRaftSim(CONFIG, write_rate=8.0, read_rate=32.0, phi=0.02,
                     seed=0)
     reps = sim.run(2)
-    _check_golden("solo_managed", sim.state, reps, golden["solo_managed"])
+    _check_golden("solo_managed", sim.state, reps, golden["solo_managed"],
+                  golden_note(golden))
 
 
-def test_closed_loop_fleet_bit_identical_to_golden():
+def test_closed_loop_fleet_bit_identical_to_golden(golden_note):
     """The fixed-role fleet scan (batched members, one of them plain
     Raft) replays its pre-PR trajectory through the widened cfg_c."""
     golden = json.loads(GOLDEN.read_text())
@@ -78,9 +82,9 @@ def test_closed_loop_fleet_bit_identical_to_golden():
     for m, (member_reports, gm) in enumerate(
             zip(fleet.reports, g["reports"])):
         _check_golden(f"fleet_fixed[{m}]", {}, member_reports,
-                      {"reports": gm, "state": {}})
+                      {"reports": gm, "state": {}}, golden_note(golden))
     _check_golden("fleet_fixed", fleet.state, [],
-                  {"reports": [], "state": g["state"]})
+                  {"reports": [], "state": g["state"]}, golden_note(golden))
 
 
 # ------------------------------------------------------------------ #

@@ -71,25 +71,27 @@ def _state_match(gstate, state):
 # --------------------------------------------------------------------- #
 # satellite 1: golden bit-identity + zero-recompile toggles
 # --------------------------------------------------------------------- #
-def test_trace_off_is_bit_identical_solo():
+def test_trace_off_is_bit_identical_solo(golden_note):
     """The pre-instrumentation solo trajectory, replayed through the
     instrumented code with tracing off: reports and every state leaf
     hash must match exactly — emit's scatter is provably inert at
     trace_on=0."""
-    g = json.loads(GOLDEN.read_text())["solo_managed"]
+    fixture = json.loads(GOLDEN.read_text())
+    g = fixture["solo_managed"]
     sim = BWRaftSim(CONFIG, write_rate=8.0, read_rate=32.0, phi=0.02,
                     seed=0)
     reps = sim.run(len(g["reports"]))
     ok, why = _reports_match(g["reports"], reps)
-    assert ok, f"report field diverged: {why}"
+    assert ok, f"report field diverged: {why}; {golden_note(fixture)}"
     ok, why = _state_match(g["state"], sim.state)
-    assert ok, f"state leaf diverged: {why}"
+    assert ok, f"state leaf diverged: {why}; {golden_note(fixture)}"
 
 
-def test_trace_off_is_bit_identical_fleet():
+def test_trace_off_is_bit_identical_fleet(golden_note):
     """Same gate for the fixed-role fleet recipe — the vmapped rings
     and the grouped-reduction plumbing must be equally inert."""
-    g = json.loads(GOLDEN.read_text())["fleet_fixed"]
+    fixture = json.loads(GOLDEN.read_text())
+    g = fixture["fleet_fixed"]
     fleet = FleetSim([
         MemberSpec(cfg=CONFIG, write_rate=6.0, read_rate=24.0, seed=1,
                    manage_resources=False, prelease=(2, 6)),
@@ -98,9 +100,10 @@ def test_trace_off_is_bit_identical_fleet():
     fleet.run(len(g["reports"][0]))
     for greports, member in zip(g["reports"], fleet.reports):
         ok, why = _reports_match(greports, member)
-        assert ok, f"fleet report field diverged: {why}"
+        assert ok, \
+            f"fleet report field diverged: {why}; {golden_note(fixture)}"
     ok, why = _state_match(g["state"], fleet.state)
-    assert ok, f"fleet state leaf diverged: {why}"
+    assert ok, f"fleet state leaf diverged: {why}; {golden_note(fixture)}"
 
 
 def test_trace_toggle_never_recompiles_solo():
