@@ -74,33 +74,6 @@ def require(ok, message: str) -> None:
         raise SmokeFailure(message)
 
 
-class CompileClock:
-    """Sums JAX's own compile-duration events (tracing, lowering,
-    backend compile) and counts persistent-cache hits and misses."""
-    EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
-              "/jax/core/compile/jaxpr_to_mlir_module_duration",
-              "/jax/core/compile/backend_compile_duration")
-
-    def __init__(self):
-        import jax
-        self.seconds, self.hits, self.misses = 0.0, 0, 0
-
-        def on_duration(event, secs, **_):
-            if event in self.EVENTS:
-                self.seconds += secs
-
-        def on_event(event, **_):
-            if event == "/jax/compilation_cache/cache_hits":
-                self.hits += 1
-            elif event == "/jax/compilation_cache/cache_misses":
-                self.misses += 1
-        jax.monitoring.register_event_duration_secs_listener(on_duration)
-        jax.monitoring.register_event_listener(on_event)
-
-    def snapshot(self):
-        return self.seconds, self.hits, self.misses
-
-
 def _timed(clock, fn, sync):
     """(wall seconds, compile seconds) of `fn()`, the wall clock stopped
     only once `sync()` is ready on the device."""
@@ -325,7 +298,7 @@ def main() -> None:
 
     cache_dir = compile_cache.enable()
     print(f"compile cache: {cache_dir}")
-    clock = CompileClock()
+    clock = compile_cache.clock()
     dev = jax.devices()[0]
 
     def peak():

@@ -44,6 +44,9 @@ The host-side control plane (Algorithm 1 "peek", MCSA "peak" leasing)
 still runs per member between epochs, reusing `runtime.ClusterController`
 — it reads the (N,) role/alive vectors from the digest and writes back
 only the four (B, N) role/wiring arrays for the members that manage.
+Each part of a digest-path epoch on the host runs under its profiler
+span (`trace.spans.FLEET_SPANS`): dispatch, digest fetch, control
+plane, write-back, flight-recorder drain.
 
 Shard groups (DESIGN.md §9): members with `group_id >= 0` are the shards
 of ONE Multi-Raft system.  The epoch function reduces their digests to
@@ -73,6 +76,7 @@ from repro.kernels import resolve_backend
 from repro.kernels.group_digest import ops as gd_ops
 from repro.trace import export as trace_export
 from repro.trace import ring as trace_ring
+from repro.trace import spans as trace_spans
 
 # static scalars every member must agree on (baked into the compiled
 # program; per-node capacities from state.build_static)
@@ -638,6 +642,29 @@ class FleetSim:
             subs.append(sub)
         return jnp.stack(subs)
 
+    def epoch_hlo(self) -> str:
+        """HLO text of the compiled digest-path epoch: the instruction
+        names a device trace gives its op events, each with this code's
+        `op_name` metadata, for mapping a trace to the tick's phases
+        (`trace.spans.hlo_op_scopes`, DESIGN.md §14).  Runs nothing and
+        changes no state.  The program that ran may carry stale
+        metadata: the in-process caches reuse an earlier trace, and the
+        persistent cache's key leaves metadata out.  So this traces the
+        epoch afresh and compiles it with the metadata in the key (an
+        entry of its own); the instructions are the same either way."""
+        fn = jax.jit(_vmapped_epoch(self.shapes, self._shared, self.backend,
+                                    self.n_groups), donate_argnums=(0,))
+        rngs = jnp.stack([jnp.zeros_like(m.rng) for m in self.members])
+        lowered = fn.lower(self._state, rngs, self._bstatic, self._cfg_c,
+                           *self._epoch_args())
+        key = "jax_compilation_cache_include_metadata_in_key"
+        was = getattr(jax.config, key)
+        jax.config.update(key, True)
+        try:
+            return lowered.compile().as_text()
+        finally:
+            jax.config.update(key, was)
+
     # ------------------------------------------------------------------ #
     def _epoch_args(self) -> Tuple:
         return ((self._gids,) if self.n_groups else ())
@@ -660,19 +687,45 @@ class FleetSim:
     def run_epoch(self) -> List[EpochReport]:
         if self.pipeline == "host":
             return self._run_epoch_host()
-        rngs = self._split_epoch_rngs()
-        self._state, digest = self._epoch_fn(self._state, rngs,
-                                             self._bstatic, self._cfg_c,
-                                             *self._epoch_args())
-        dg = jax.tree.map(np.asarray, digest)
+        with jax.profiler.TraceAnnotation(trace_spans.FLEET_DISPATCH):
+            rngs = self._split_epoch_rngs()
+            self._state, digest = self._epoch_fn(self._state, rngs,
+                                                 self._bstatic, self._cfg_c,
+                                                 *self._epoch_args())
+        with jax.profiler.TraceAnnotation(trace_spans.FLEET_FETCH):
+            dg = jax.tree.map(np.asarray, digest)
         self.d2h_bytes += pytree_nbytes(dg)
         if self.n_groups:
             self.last_group_digest = dg.pop("group")
-            self._append_group_reports(self.last_group_digest)
+            with jax.profiler.TraceAnnotation(trace_spans.FLEET_CONTROL):
+                self._append_group_reports(self.last_group_digest)
         self.last_digest = dg
         if bool(np.asarray(self._cfg_c["trace_on"]).any()):
             self.drain_trace()
+        with jax.profiler.TraceAnnotation(trace_spans.FLEET_CONTROL):
+            out, managed_rows, managed_vals = self._control(dg)
+        if managed_rows:
+            # write back ONLY the managed members' role/wiring rows — the
+            # rest of the state never leaves (or re-enters) the device
+            with jax.profiler.TraceAnnotation(trace_spans.FLEET_WRITEBACK):
+                idx = jnp.asarray(managed_rows, jnp.int32)
+                upd = {name: jnp.asarray(np.stack([v[j]
+                                                   for v in managed_vals]))
+                       for j, name in enumerate(("role", "alive", "sec_of",
+                                                 "obs_of"))}
+                self._state = dict(
+                    self._state,
+                    **{name: self._state[name].at[idx].set(arr)
+                       for name, arr in upd.items()})
+        return out
 
+    def _control(self, dg: Dict) -> Tuple[List[EpochReport], List[int],
+                                          List[Tuple]]:
+        """The host control plane of one digest-path epoch: each member's
+        report, Algorithm 1 and the warned-aware MCSA lease for the
+        members that manage, then the bid policies.  Returns the reports
+        and the managed members' rows and leased (role, alive, sec_of,
+        obs_of) values."""
         managed_rows: List[int] = []
         managed_vals: List[Tuple] = []
         out = []
@@ -703,19 +756,7 @@ class FleetSim:
             m.reports.append(rep)
             out.append(rep)
         self._apply_bid_policies()
-
-        if managed_rows:
-            # write back ONLY the managed members' role/wiring rows — the
-            # rest of the state never leaves (or re-enters) the device
-            idx = jnp.asarray(managed_rows, jnp.int32)
-            upd = {name: jnp.asarray(np.stack([v[j] for v in managed_vals]))
-                   for j, name in enumerate(("role", "alive", "sec_of",
-                                             "obs_of"))}
-            self._state = dict(
-                self._state,
-                **{name: self._state[name].at[idx].set(arr)
-                   for name, arr in upd.items()})
-        return out
+        return out, managed_rows, managed_vals
 
     def _run_epoch_host(self) -> List[EpochReport]:
         """PR-1 reference epoch: full state + per-tick metric stacks are
@@ -809,15 +850,17 @@ class FleetSim:
         """One D2H fetch of every member's ring + cursors; returns (and
         appends to `trace_events`) the events since the last drain, in
         per-member emission order (DESIGN.md §14)."""
-        ev = np.asarray(self._state["trace_ev"])
-        pos = np.asarray(self._state["trace_pos"])
-        emit = np.asarray(self._state["trace_emit"])
-        self.d2h_bytes += ev.nbytes + pos.nbytes + emit.nbytes
-        new: List[trace_export.TraceEvent] = []
-        for i, cur in enumerate(self._trace_cursors):
-            new.extend(cur.drain({"trace_ev": ev[i], "trace_pos": pos[i],
-                                  "trace_emit": emit[i]}))
-        self.trace_events.extend(new)
+        with jax.profiler.TraceAnnotation(trace_spans.FLEET_DRAIN):
+            ev = np.asarray(self._state["trace_ev"])
+            pos = np.asarray(self._state["trace_pos"])
+            emit = np.asarray(self._state["trace_emit"])
+            self.d2h_bytes += ev.nbytes + pos.nbytes + emit.nbytes
+            new: List[trace_export.TraceEvent] = []
+            for i, cur in enumerate(self._trace_cursors):
+                new.extend(cur.drain({"trace_ev": ev[i],
+                                      "trace_pos": pos[i],
+                                      "trace_emit": emit[i]}))
+            self.trace_events.extend(new)
         return new
 
     @property
@@ -888,10 +931,13 @@ class FleetSim:
                                     self.fault_ticks))
         # identical split order to the epoch-by-epoch path, so the two are
         # trajectory-equal at the same seeds (tests/test_fleet.py)
-        rngs = jnp.stack([self._split_epoch_rngs() for _ in range(epochs)])
-        self._state, digests = fn(self._state, rngs, self._bstatic,
-                                  self._cfg_c, *self._epoch_args())
-        dg = jax.tree.map(np.asarray, digests)
+        with jax.profiler.TraceAnnotation(trace_spans.FLEET_DISPATCH):
+            rngs = jnp.stack([self._split_epoch_rngs()
+                              for _ in range(epochs)])
+            self._state, digests = fn(self._state, rngs, self._bstatic,
+                                      self._cfg_c, *self._epoch_args())
+        with jax.profiler.TraceAnnotation(trace_spans.FLEET_FETCH):
+            dg = jax.tree.map(np.asarray, digests)
         self.d2h_bytes += pytree_nbytes(dg)
         gdg = dg.pop("group") if self.n_groups else None
         self.last_digest = {k: v[-1] for k, v in dg.items()}

@@ -46,6 +46,7 @@ from repro.core.state import (DEAD, FOLLOWER, LEADER, OBSERVER, SECRETARY,
 from repro.trace import export as trace_export
 from repro.trace import metrics as trace_metrics
 from repro.trace import ring as trace_ring
+from repro.trace import spans as trace_spans
 from repro.workload import arrivals as workload_arrivals
 
 
@@ -505,13 +506,18 @@ def device_epoch(state: Dict, static, cfg_c: Dict, rng, T: int, *,
     def body(carry, r):
         st, acc = carry
         st, m = step_mod.tick(st, static, cfg_c, r, backend=backend)
-        return (st, _digest_acc_update(acc, m)), None
+        with jax.named_scope(trace_spans.EPOCH_DIGEST):
+            acc = _digest_acc_update(acc, m)
+        return (st, acc), None
 
     rngs = jax.random.split(rng, T)
     (state, acc), _ = jax.lax.scan(body, (state, _digest_acc_init(lt0)),
                                    rngs)
-    digest = _finalize_digest(state, acc, cost_before, T, cfg_c)
-    return compact_state(state), digest
+    with jax.named_scope(trace_spans.EPOCH_DIGEST):
+        digest = _finalize_digest(state, acc, cost_before, T, cfg_c)
+    with jax.named_scope(trace_spans.EPOCH_COMPACT):
+        state = compact_state(state)
+    return state, digest
 
 
 def hist_percentile(counts: np.ndarray, q: float) -> float:

@@ -42,6 +42,7 @@ from repro.kernels.raft_tick import ops as rt_ops
 from repro.market import synthetic as market_synth
 from repro.trace import metrics as trace_metrics
 from repro.trace import ring as trace_ring
+from repro.trace import spans as trace_spans
 
 
 def _rand(rng, n):
@@ -1174,6 +1175,12 @@ def cost_step(state, static, cfg_c):
     return dict(state, cost_accrued=state["cost_accrued"] + per_tick)
 
 
+def _phase(name: str):
+    """The named scope of one tick phase."""
+    assert name in trace_spans.TICK_PHASES, name
+    return jax.named_scope(f"tick.{name}")
+
+
 def tick(state, static, cfg_c, rng, *, reference=False,
          backend="xla") -> Tuple[Dict, Dict]:
     """One full protocol tick. Returns (state, per-tick metrics).
@@ -1195,21 +1202,35 @@ def tick(state, static, cfg_c, rng, *, reference=False,
     # that predate the reference split (fan-out, anti-entropy)
     hot = "xla" if reference else backend
     r_spot, r_work, r_lead, r_elec = jax.random.split(rng, 4)
-    state, killed = spot_step(state, static, cfg_c, r_spot)
-    state, (n_w, n_r, r_key) = workload_step(state, static, cfg_c, r_work)
-    state = election_step(state, static, cfg_c, r_elec)
-    state = leader_step(state, static, cfg_c, r_lead, backend=hot)
-    state = follower_step(state, static, cfg_c, reference=reference,
-                          backend=backend)
-    state = commit_step(state, static, cfg_c, reference=reference,
-                        backend=backend)
-    state = apply_step(state, static, cfg_c, reference=reference,
-                       backend=backend)
-    state = observer_sync_step(state, static, cfg_c)
-    state = anti_entropy_step(state, static, cfg_c, backend=hot)
-    state, (read_served, read_lat, obs_served, obs_stale) = \
-        read_step(state, static, cfg_c)
-    state = cost_step(state, static, cfg_c)
+    # each phase under its named scope (`trace.spans.TICK_SCOPES`): op
+    # metadata only, so a device trace's ops map back to their phase
+    with _phase("spot"):
+        state, killed = spot_step(state, static, cfg_c, r_spot)
+    with _phase("workload"):
+        state, (n_w, n_r, r_key) = workload_step(state, static, cfg_c,
+                                                 r_work)
+    with _phase("election"):
+        state = election_step(state, static, cfg_c, r_elec)
+    with _phase("leader"):
+        state = leader_step(state, static, cfg_c, r_lead, backend=hot)
+    with _phase("follower"):
+        state = follower_step(state, static, cfg_c, reference=reference,
+                              backend=backend)
+    with _phase("commit"):
+        state = commit_step(state, static, cfg_c, reference=reference,
+                            backend=backend)
+    with _phase("apply"):
+        state = apply_step(state, static, cfg_c, reference=reference,
+                           backend=backend)
+    with _phase("observer_sync"):
+        state = observer_sync_step(state, static, cfg_c)
+    with _phase("anti_entropy"):
+        state = anti_entropy_step(state, static, cfg_c, backend=hot)
+    with _phase("read"):
+        state, (read_served, read_lat, obs_served, obs_stale) = \
+            read_step(state, static, cfg_c)
+    with _phase("cost"):
+        state = cost_step(state, static, cfg_c)
     state = dict(state, tick=state["tick"] + 1)
 
     lid = leader_id(state, static)

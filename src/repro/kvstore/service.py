@@ -17,6 +17,11 @@ resident read histogram (`state["read_lat_hist"]`), the same unit-bin
 digest histogram the simulator's aggregate read path samples into.
 This is the host-facing service layer used by the examples; throughput-
 scale experiments drive the simulator's aggregate workload instead.
+
+Profiler spans (`trace.spans`, DESIGN.md §14): `kv.tick` around each
+tick dispatch, `kv.sync` around each blocking device-to-host read,
+`kv.write` around each host-issued device write; `host_reads` counts
+the reads.
 """
 from __future__ import annotations
 
@@ -30,6 +35,7 @@ import jax.numpy as jnp
 
 from repro.core import state as SM
 from repro.core.runtime import BWRaftSim
+from repro.trace import spans as trace_spans
 
 
 class NotLeader(Exception):
@@ -56,12 +62,8 @@ class BWKVService:
         # per-request read latencies (ticks), in completion order — the
         # host-side twin of the device read histogram (DESIGN.md §11)
         self.read_latencies: list = []
-        # client-round annotations for the flight-recorder Perfetto
-        # export (DESIGN.md §14): one span dict per completed put /
-        # read-index round ({name, start_tick, end_tick, ...args}),
-        # passed straight to `trace.export.to_perfetto(annotations=...)`
-        # to land on the "client" track next to the device events
-        self.annotations: list = []
+        # blocking device-to-host reads issued so far (`_fetch`)
+        self.host_reads: int = 0
         # session fence floor: the highest log length this client has
         # been acked (writes) or served (reads).  A read-index round
         # fences at max(leader commit index, floor), so a read can never
@@ -69,6 +71,12 @@ class BWKVService:
         # session — even across a leader change whose fresh leader has
         # not re-established the old commit index yet (DESIGN.md §11).
         self.session_floor: int = 0
+
+    def _fetch(self, x) -> np.ndarray:
+        """One blocking device-to-host read, counted in `host_reads`."""
+        with jax.profiler.TraceAnnotation(trace_spans.KV_SYNC):
+            self.host_reads += 1
+            return np.asarray(x)
 
     def _key_id(self, key: str) -> int:
         K = self.sim.cfg.key_space
@@ -85,51 +93,52 @@ class BWKVService:
                 lambda s, c, r: step_mod.tick(s, static, c, r,
                                               backend=backend))
         for _ in range(n):
-            self.sim.rng, sub = jax.random.split(self.sim.rng)
-            self.sim.state, _ = self._tickfn(self.sim.state,
-                                             self.sim.cfg_c, sub)
+            with jax.profiler.TraceAnnotation(trace_spans.KV_TICK):
+                self.sim.rng, sub = jax.random.split(self.sim.rng)
+                self.sim.state, _ = self._tickfn(self.sim.state,
+                                                 self.sim.cfg_c, sub)
 
     def put(self, key: str, value: int) -> PutResult:
         """Submit a write through the leader; block until committed."""
         kid = self._key_id(key)
         st = self.sim.state
-        lid = int(SM.leader_id(st, self.sim.static))
+        lid = int(self._fetch(SM.leader_id(st, self.sim.static)))
         waited = 0
         while lid < 0:
             self._step(5)
             waited += 5
             if waited > self.timeout:
                 raise Timeout("no leader elected")
-            lid = int(SM.leader_id(self.sim.state, self.sim.static))
+            lid = int(self._fetch(SM.leader_id(self.sim.state,
+                                               self.sim.static)))
         st = self.sim.state
         # append directly at the leader (bypasses the random workload gen —
         # this is the explicit-client path)
-        pos = int(st["log_len"][lid])
+        pos = int(self._fetch(st["log_len"][lid]))
         if pos >= self.sim.cfg.max_log:
             raise Timeout("log window full; run an epoch to compact")
-        term = st["term"][lid]
-        self.sim.state = dict(
-            st,
-            log_term=st["log_term"].at[lid, pos].set(term),
-            log_key=st["log_key"].at[lid, pos].set(kid),
-            log_val=st["log_val"].at[lid, pos].set(value),
-            log_len=st["log_len"].at[lid].set(pos + 1),
-            entry_submit_t=st["entry_submit_t"].at[pos].set(st["tick"]),
-        )
-        t0 = int(self.sim.state["tick"])
+        with jax.profiler.TraceAnnotation(trace_spans.KV_WRITE):
+            term = st["term"][lid]
+            self.sim.state = dict(
+                st,
+                log_term=st["log_term"].at[lid, pos].set(term),
+                log_key=st["log_key"].at[lid, pos].set(kid),
+                log_val=st["log_val"].at[lid, pos].set(value),
+                log_len=st["log_len"].at[lid].set(pos + 1),
+                entry_submit_t=st["entry_submit_t"].at[pos].set(st["tick"]),
+            )
+        t0 = int(self._fetch(self.sim.state["tick"]))
         while True:
             self._step(1)
             st = self.sim.state
-            lid_now = int(SM.leader_id(st, self.sim.static))
-            if lid_now >= 0 and int(st["commit_len"][lid_now]) > pos:
+            lid_now = int(self._fetch(SM.leader_id(st, self.sim.static)))
+            if lid_now >= 0 and \
+                    int(self._fetch(st["commit_len"][lid_now])) > pos:
                 self.session_floor = max(self.session_floor, pos + 1)
-                self.annotations.append({
-                    "name": f"put {key}", "start_tick": t0,
-                    "end_tick": int(st["tick"]), "revision": pos,
-                    "leader": lid_now})
-                return PutResult(revision=pos,
-                                 latency_ticks=int(st["tick"]) - t0)
-            if int(st["tick"]) - t0 > self.timeout:
+                return PutResult(
+                    revision=pos,
+                    latency_ticks=int(self._fetch(st["tick"])) - t0)
+            if int(self._fetch(st["tick"])) - t0 > self.timeout:
                 raise Timeout(f"put({key}) not committed "
                               f"after {self.timeout} ticks")
 
@@ -143,14 +152,15 @@ class BWKVService:
         st = self.sim.state
         H = st["read_lat_hist"].shape[0]
         b = min(max(int(latency_ticks), 0), H - 1)
-        self.sim.state = dict(
-            st,
-            reads_served=st["reads_served"] + 1,
-            read_lat_sum=st["read_lat_sum"] + float(latency_ticks),
-            read_lat_max=jnp.maximum(st["read_lat_max"],
-                                     float(latency_ticks)),
-            read_lat_hist=st["read_lat_hist"].at[b].add(1),
-        )
+        with jax.profiler.TraceAnnotation(trace_spans.KV_WRITE):
+            self.sim.state = dict(
+                st,
+                reads_served=st["reads_served"] + 1,
+                read_lat_sum=st["read_lat_sum"] + float(latency_ticks),
+                read_lat_max=jnp.maximum(st["read_lat_max"],
+                                         float(latency_ticks)),
+                read_lat_hist=st["read_lat_hist"].at[b].add(1),
+            )
 
     def get(self, key: str, *, allow_observer: bool = True,
             wait_for_leader: bool = False) -> Tuple[int, int]:
@@ -175,8 +185,8 @@ class BWKVService:
         round's latency (ticks from request to serve) is recorded via
         `_record_read`."""
         kid = self._key_id(key)
-        t0 = int(self.sim.state["tick"])
-        lid = int(SM.leader_id(self.sim.state, self.sim.static))
+        t0 = int(self._fetch(self.sim.state["tick"]))
+        lid = int(self._fetch(SM.leader_id(self.sim.state, self.sim.static)))
         if lid < 0 and not wait_for_leader:
             raise NotLeader("no leader for readindex")
         waited = 0
@@ -185,12 +195,14 @@ class BWKVService:
             waited += 5
             if waited > self.timeout:
                 raise Timeout("read: no leader elected")
-            lid = int(SM.leader_id(self.sim.state, self.sim.static))
+            lid = int(self._fetch(SM.leader_id(self.sim.state,
+                                               self.sim.static)))
         st = self.sim.state
-        role = np.asarray(st["role"])
-        alive = np.asarray(st["alive"])
-        readindex = max(int(st["commit_len"][lid]), self.session_floor)
-        applied = np.asarray(st["applied_len"])
+        role = self._fetch(st["role"])
+        alive = self._fetch(st["alive"])
+        readindex = max(int(self._fetch(st["commit_len"][lid])),
+                        self.session_floor)
+        applied = self._fetch(st["applied_len"])
         node = None
         if allow_observer:
             obs = np.where((role == SM.OBSERVER) & alive &
@@ -203,18 +215,15 @@ class BWKVService:
             node = int(fol[0]) if fol.size else lid
         # apply-index wait: the serving replica must reach the fence
         waited = 0
-        while int(self.sim.state["applied_len"][node]) < readindex:
+        while int(self._fetch(self.sim.state["applied_len"][node])) < \
+                readindex:
             self._step(1)
             waited += 1
             if waited > self.timeout:
                 raise Timeout("read: node never reached readindex")
-        value = int(self.sim.state["kv"][node, kid])
+        value = int(self._fetch(self.sim.state["kv"][node, kid]))
         self.session_floor = max(self.session_floor, readindex)
-        self.annotations.append({
-            "name": f"read {key}", "start_tick": t0,
-            "end_tick": int(self.sim.state["tick"]),
-            "fence": readindex, "node": node})
-        self._record_read(int(self.sim.state["tick"]) - t0)
+        self._record_read(int(self._fetch(self.sim.state["tick"])) - t0)
         return value, readindex
 
     def get_stale(self, key: str) -> Tuple[int, int]:
@@ -239,11 +248,11 @@ class BWKVService:
         if O == 0:
             return self.get(key)
         kid = self._key_id(key)
-        t0 = int(st["tick"])
-        alive = np.asarray(st["dobs_alive"])
-        applied = np.asarray(st["dobs_applied"])
-        synced = np.asarray(st["dobs_synced_t"])
-        bound = int(self.sim.cfg_c["staleness_bound"])
+        t0 = int(self._fetch(st["tick"]))
+        alive = self._fetch(st["dobs_alive"])
+        applied = self._fetch(st["dobs_applied"])
+        synced = self._fetch(st["dobs_synced_t"])
+        bound = int(self._fetch(self.sim.cfg_c["staleness_bound"]))
         ok = alive & (t0 - synced <= bound) & (applied >= self.session_floor)
         cand = np.where(ok)[0]
         if not cand.size:
@@ -251,15 +260,11 @@ class BWKVService:
         # freshest qualifying observer serves
         o = int(cand[np.argmax(applied[cand])])
         revision = int(applied[o])
-        fol = int(st["dobs_fol"][o])
-        keys = np.asarray(st["log_key"][fol][:revision])
-        vals = np.asarray(st["log_val"][fol][:revision])
+        fol = int(self._fetch(st["dobs_fol"][o]))
+        keys = self._fetch(st["log_key"][fol][:revision])
+        vals = self._fetch(st["log_val"][fol][:revision])
         hits = np.where(keys == kid)[0]
         value = int(vals[hits[-1]]) if hits.size else -1
         self.session_floor = max(self.session_floor, revision)
-        self.annotations.append({
-            "name": f"read.stale {key}", "start_tick": t0,
-            "end_tick": int(self.sim.state["tick"]),
-            "revision": revision, "observer": o})
-        self._record_read(int(self.sim.state["tick"]) - t0)
+        self._record_read(int(self._fetch(self.sim.state["tick"])) - t0)
         return value, revision

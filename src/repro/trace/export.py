@@ -146,8 +146,9 @@ def to_perfetto(events: Sequence[TraceEvent], *, ticks: int = 0,
     handoff/2PC instants), tid = site track for anti-entropy rounds
     (via `obs_site[member][slot]`, the static `dobs_site` wiring), and
     a per-member "leader" thread of `"X"` tenure spans whose gaps are
-    the leaderless windows.  `annotations` (from
-    `kvstore/service.py`) land on a "client" thread as spans."""
+    the leaderless windows.  `annotations`, the caller's own span dicts
+    in ticks ({name, start_tick, end_tick, member?, ...args}), land on
+    a "client" thread as spans."""
     tev: List[Dict] = []
     members = sorted({e.member for e in events}) or [0]
     horizon = max([ticks] + [e.tick + 1 for e in events])

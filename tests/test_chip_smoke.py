@@ -36,8 +36,9 @@ def cfg():
 
 
 @pytest.fixture(scope="module")
-def clock(smoke):
-    return smoke.CompileClock()
+def clock():
+    from repro import compile_cache
+    return compile_cache.clock()
 
 
 def test_device_check_refuses_cpu(smoke, capsys):
