@@ -18,24 +18,45 @@ digest histogram the simulator's aggregate read path samples into.
 This is the host-facing service layer used by the examples; throughput-
 scale experiments drive the simulator's aggregate workload instead.
 
+Each request is one compiled device program (DESIGN.md §11): its leader
+wait, its append or fence and replica pick, and its wait for commit or
+apply run as one `lax.while_loop` that steps the cluster one tick per
+iteration, exactly as a host loop over `step.tick` would, with the same
+key split off the cluster's running key each tick.  The host dispatches
+the program and reads back one small answer, so a request costs one
+dispatch and one blocking read however many ticks it waits.
+
 Profiler spans (`trace.spans`, DESIGN.md §14): `kv.tick` around each
-tick dispatch, `kv.sync` around each blocking device-to-host read,
-`kv.write` around each host-issued device write; `host_reads` counts
-the reads.
+program dispatch, `kv.sync` around each blocking device-to-host read,
+`kv.write` around each host-issued device write (`get_stale`'s read
+record); `host_reads` counts the reads, `dispatches` the programs and
+`device_ticks` the ticks stepped inside them.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
-from typing import Optional, Tuple
+from typing import Tuple
 
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.core import state as SM
+from repro.core import step
 from repro.core.runtime import BWRaftSim
 from repro.trace import spans as trace_spans
+
+# a request's outcome, the first word of its program's answer
+OK, NO_LEADER, TIMEOUT_LEADER, LOG_FULL, TIMEOUT_COMMIT, TIMEOUT_APPLY = \
+    range(6)
+# still waiting: for a leader, or for the entry to commit or the serving
+# replica to apply the fence
+_LEADER_WAIT, _WAIT = -2, -1
+# a request with no leader looks for one every this many ticks
+_LEADER_POLL = 5
 
 
 class NotLeader(Exception):
@@ -52,18 +73,168 @@ class PutResult:
     latency_ticks: int
 
 
+# ------------------------------------------------------ device programs
+def _advance(static, backend, state, cfg_c, rng):
+    """One tick as the service steps it: a fresh key split off the
+    cluster's running key.  `cfg_c` and `rng` stay traced, so a rate
+    change reaches the next tick without a recompile."""
+    rng, sub = jax.random.split(rng)
+    state, _ = step.tick(state, static, cfg_c, sub, backend=backend)
+    return state, rng
+
+
+def _ticks(static, backend, state, cfg_c, rng, n):
+    """`n` ticks (traced, so one program serves every count)."""
+    return lax.fori_loop(
+        0, n, lambda _, c: _advance(static, backend, c[0], cfg_c, c[1]),
+        (state, rng))
+
+
+def _wait(static, backend, state, cfg_c, rng, timeout, status, extra,
+          settle, check):
+    """Step the cluster a tick at a time until the request's status is
+    final (>= 0).  While `_LEADER_WAIT`, every `_LEADER_POLL` ticks the
+    request times out once it has waited past `timeout`, else `settle`
+    looks for a leader and, finding one, moves it on; while `_WAIT`,
+    `check` decides after each tick.  `waited` restarts when the leader
+    is found; timeouts count ticks stepped, which is what `tick`
+    advances by, so the loop ends whatever the state holds.  Returns the
+    state, the key, the status, the ticks waited since the leader was
+    found, the ticks stepped and `extra`."""
+    def body(c):
+        s, r, status, waited, n, extra = c
+        waiting = status == _WAIT
+        s, r = _advance(static, backend, s, cfg_c, r)
+        waited, n = waited + 1, n + 1
+        poll = (status == _LEADER_WAIT) & (waited % _LEADER_POLL == 0)
+        status = jnp.where(poll & (waited > timeout), TIMEOUT_LEADER, status)
+        s, settled, extra = settle(s, poll & (status == _LEADER_WAIT),
+                                   status, extra)
+        waited = jnp.where(settled != status, 0, waited)
+        status = jnp.where(waiting, check(s, waited, extra), settled)
+        return s, r, status, waited, n, extra
+    z = jnp.int32(0)
+    return lax.while_loop(lambda c: c[2] < 0, body,
+                          (state, rng, status, z, z, extra))
+
+
+def _put(static, backend, max_log, state, cfg_c, rng, kid, value, timeout):
+    """One put: wait for a leader, append at the end of its log (or stop
+    at the log window), then wait until the leader's commit index covers
+    the entry.  Answer: (status, position, ticks from the append to the
+    commit, ticks stepped)."""
+    def settle(s, now, status, extra):
+        lid = SM.leader_id(s, static)
+        found = now & (lid >= 0)
+        ld = jnp.maximum(lid, 0)
+        pos = s["log_len"][ld]
+        go = found & (pos < max_log)
+        at = jnp.where(go, pos, s["entry_submit_t"].shape[0])  # dropped
+        s = dict(
+            s,
+            log_term=s["log_term"].at[ld, at].set(s["term"][ld],
+                                                  mode="drop"),
+            log_key=s["log_key"].at[ld, at].set(kid, mode="drop"),
+            log_val=s["log_val"].at[ld, at].set(value, mode="drop"),
+            log_len=s["log_len"].at[ld].add(go.astype(jnp.int32)),
+            entry_submit_t=s["entry_submit_t"].at[at].set(s["tick"],
+                                                          mode="drop"))
+        status = jnp.where(found, jnp.where(go, _WAIT, LOG_FULL), status)
+        return s, status, (jnp.where(found, pos, extra[0]),)
+
+    def committed(s, waited, extra):
+        lid = SM.leader_id(s, static)
+        done = (lid >= 0) & (s["commit_len"][jnp.maximum(lid, 0)] > extra[0])
+        return jnp.where(done, OK, jnp.where(waited > timeout,
+                                             TIMEOUT_COMMIT, _WAIT))
+
+    state, status, extra = settle(state, True, _LEADER_WAIT,
+                                  (jnp.int32(-1),))
+    state, rng, status, waited, n, (pos,) = _wait(
+        static, backend, state, cfg_c, rng, timeout, status, extra, settle,
+        committed)
+    return state, rng, jnp.stack([status, pos, waited, n])
+
+
+def _fold_read(state, ok, latency):
+    """Fold one served read (where `ok`) into the cluster's read record:
+    the count, the latency sum and maximum, and the unit-bin histogram
+    the aggregate read path samples into (DESIGN.md §11)."""
+    H = state["read_lat_hist"].shape[0]
+    lat = jnp.asarray(latency).astype(state["read_lat_sum"].dtype)
+    return dict(
+        state,
+        reads_served=state["reads_served"] + jnp.asarray(ok).astype(
+            state["reads_served"].dtype),
+        read_lat_sum=jnp.where(ok, state["read_lat_sum"] + lat,
+                               state["read_lat_sum"]),
+        read_lat_max=jnp.where(ok, jnp.maximum(state["read_lat_max"], lat),
+                               state["read_lat_max"]),
+        read_lat_hist=state["read_lat_hist"].at[jnp.where(
+            ok, jnp.clip(latency, 0, H - 1), H)].add(1, mode="drop"))
+
+
+def _get(static, backend, state, cfg_c, rng, kid, floor, timeout, *,
+         allow_observer, wait_for_leader):
+    """One read-index round (`BWKVService.get`).  Answer: (status,
+    value, fence, ticks stepped), the last also the read's latency."""
+    def settle(s, now, status, extra):
+        lid = SM.leader_id(s, static)
+        found = now & (lid >= 0)
+        ld = jnp.maximum(lid, 0)
+        fence = jnp.maximum(s["commit_len"][ld], floor)
+        ready = s["alive"] & (s["applied_len"] >= fence)
+        voter = ready & ((s["role"] == SM.FOLLOWER) |
+                         (s["role"] == SM.LEADER))
+        node = jnp.where(jnp.any(voter), jnp.argmax(voter), ld)
+        if allow_observer:
+            obs = ready & (s["role"] == SM.OBSERVER)
+            node = jnp.where(jnp.any(obs), jnp.argmax(obs), node)
+        caught = s["applied_len"][node] >= fence
+        status = jnp.where(found, jnp.where(caught, OK, _WAIT), status)
+        return s, status, (jnp.where(found, fence, extra[0]),
+                           jnp.where(found, node, extra[1]).astype(jnp.int32))
+
+    def applied(s, waited, extra):
+        fence, node = extra
+        return jnp.where(waited > timeout, TIMEOUT_APPLY,
+                         jnp.where(s["applied_len"][node] >= fence, OK,
+                                   _WAIT))
+
+    state, status, extra = settle(state, True, _LEADER_WAIT,
+                                  (jnp.int32(0), jnp.int32(0)))
+    if not wait_for_leader:
+        status = jnp.where(status == _LEADER_WAIT, NO_LEADER, status)
+    state, rng, status, _, n, (fence, node) = _wait(
+        static, backend, state, cfg_c, rng, timeout, status, extra, settle,
+        applied)
+    state = _fold_read(state, status == OK, n)
+    return state, rng, jnp.stack([status, state["kv"][node, kid], fence, n])
+
+
+# ------------------------------------------------------------- service
 class BWKVService:
     """Synchronous client over an in-process BW-Raft cluster."""
 
     def __init__(self, sim: BWRaftSim, *, timeout_ticks: int = 400):
         self.sim = sim
         self.timeout = timeout_ticks
-        self._tickfn = None
+        static, backend = sim.static, sim.backend
+        self._ticks = jax.jit(functools.partial(_ticks, static, backend))
+        self._put = jax.jit(functools.partial(_put, static, backend,
+                                              sim.cfg.max_log))
+        self._get = jax.jit(functools.partial(_get, static, backend),
+                            static_argnames=("allow_observer",
+                                             "wait_for_leader"))
         # per-request read latencies (ticks), in completion order — the
         # host-side twin of the device read histogram (DESIGN.md §11)
         self.read_latencies: list = []
         # blocking device-to-host reads issued so far (`_fetch`)
         self.host_reads: int = 0
+        # device programs dispatched (one per request, one per `_step`)
+        # and the cluster ticks stepped inside them
+        self.dispatches: int = 0
+        self.device_ticks: int = 0
         # session fence floor: the highest log length this client has
         # been acked (writes) or served (reads).  A read-index round
         # fences at max(leader commit index, floor), so a read can never
@@ -83,84 +254,57 @@ class BWKVService:
         return int(hashlib.sha1(key.encode()).hexdigest(), 16) % K
 
     def _step(self, n: int = 1) -> None:
-        """Advance the cluster `n` ticks on the sim's backend.  `cfg_c`
-        is a jit argument, so `sim.set_rates(...)` reaches the next
-        tick without a recompile."""
-        import repro.core.step as step_mod
-        if self._tickfn is None:
-            static, backend = self.sim.static, self.sim.backend
-            self._tickfn = jax.jit(
-                lambda s, c, r: step_mod.tick(s, static, c, r,
-                                              backend=backend))
-        for _ in range(n):
-            with jax.profiler.TraceAnnotation(trace_spans.KV_TICK):
-                self.sim.rng, sub = jax.random.split(self.sim.rng)
-                self.sim.state, _ = self._tickfn(self.sim.state,
-                                                 self.sim.cfg_c, sub)
+        """Advance the cluster `n` ticks on the sim's backend, in one
+        dispatch.  `cfg_c` is a jit argument, so `sim.set_rates(...)`
+        reaches the next tick without a recompile."""
+        sim = self.sim
+        with jax.profiler.TraceAnnotation(trace_spans.KV_TICK):
+            sim.state, sim.rng = self._ticks(sim.state, sim.cfg_c, sim.rng,
+                                             n)
+        self.dispatches += 1
+        self.device_ticks += n
+
+    def _request(self, program, *args, **static_args) -> list:
+        """Dispatch one request program over the cluster, keep the state
+        and key it leaves (a failed request leaves the ticks it stepped
+        behind), and read its answer: one dispatch, one read."""
+        sim = self.sim
+        with jax.profiler.TraceAnnotation(trace_spans.KV_TICK):
+            sim.state, sim.rng, answer = program(
+                sim.state, sim.cfg_c, sim.rng, *args, **static_args)
+        self.dispatches += 1
+        answer = [int(a) for a in self._fetch(answer)]
+        self.device_ticks += answer[-1]
+        return answer
 
     def put(self, key: str, value: int) -> PutResult:
-        """Submit a write through the leader; block until committed."""
-        kid = self._key_id(key)
-        st = self.sim.state
-        lid = int(self._fetch(SM.leader_id(st, self.sim.static)))
-        waited = 0
-        while lid < 0:
-            self._step(5)
-            waited += 5
-            if waited > self.timeout:
-                raise Timeout("no leader elected")
-            lid = int(self._fetch(SM.leader_id(self.sim.state,
-                                               self.sim.static)))
-        st = self.sim.state
-        # append directly at the leader (bypasses the random workload gen —
-        # this is the explicit-client path)
-        pos = int(self._fetch(st["log_len"][lid]))
-        if pos >= self.sim.cfg.max_log:
+        """Submit a write through the leader; block until committed.
+
+        Waits for a leader (`Timeout` after `timeout` ticks), appends at
+        the end of its log (`Timeout` if the log window is full), then
+        steps until the leader's commit index covers the entry (`Timeout`
+        after `timeout` ticks)."""
+        status, pos, latency, _ = self._request(
+            self._put, self._key_id(key), value, self.timeout)
+        if status == TIMEOUT_LEADER:
+            raise Timeout("no leader elected")
+        if status == LOG_FULL:
             raise Timeout("log window full; run an epoch to compact")
-        with jax.profiler.TraceAnnotation(trace_spans.KV_WRITE):
-            term = st["term"][lid]
-            self.sim.state = dict(
-                st,
-                log_term=st["log_term"].at[lid, pos].set(term),
-                log_key=st["log_key"].at[lid, pos].set(kid),
-                log_val=st["log_val"].at[lid, pos].set(value),
-                log_len=st["log_len"].at[lid].set(pos + 1),
-                entry_submit_t=st["entry_submit_t"].at[pos].set(st["tick"]),
-            )
-        t0 = int(self._fetch(self.sim.state["tick"]))
-        while True:
-            self._step(1)
-            st = self.sim.state
-            lid_now = int(self._fetch(SM.leader_id(st, self.sim.static)))
-            if lid_now >= 0 and \
-                    int(self._fetch(st["commit_len"][lid_now])) > pos:
-                self.session_floor = max(self.session_floor, pos + 1)
-                return PutResult(
-                    revision=pos,
-                    latency_ticks=int(self._fetch(st["tick"])) - t0)
-            if int(self._fetch(st["tick"])) - t0 > self.timeout:
-                raise Timeout(f"put({key}) not committed "
-                              f"after {self.timeout} ticks")
+        if status != OK:
+            raise Timeout(f"put({key}) not committed "
+                          f"after {self.timeout} ticks")
+        self.session_floor = max(self.session_floor, pos + 1)
+        return PutResult(revision=pos, latency_ticks=latency)
 
     def _record_read(self, latency_ticks: int) -> None:
-        """Fold one completed read into the service's latency record and
-        the cluster's device-resident read histogram — the same unit-bin
-        digest histogram the aggregate read path samples into, so client
-        reads and simulated reads share one percentile machinery
-        (DESIGN.md §11)."""
+        """Fold one read served on the host into the service's latency
+        record and the cluster's device-resident read histogram, as the
+        `get` program does on the device — so client reads and simulated
+        reads share one percentile machinery (DESIGN.md §11)."""
         self.read_latencies.append(int(latency_ticks))
-        st = self.sim.state
-        H = st["read_lat_hist"].shape[0]
-        b = min(max(int(latency_ticks), 0), H - 1)
         with jax.profiler.TraceAnnotation(trace_spans.KV_WRITE):
-            self.sim.state = dict(
-                st,
-                reads_served=st["reads_served"] + 1,
-                read_lat_sum=st["read_lat_sum"] + float(latency_ticks),
-                read_lat_max=jnp.maximum(st["read_lat_max"],
-                                         float(latency_ticks)),
-                read_lat_hist=st["read_lat_hist"].at[b].add(1),
-            )
+            self.sim.state = _fold_read(self.sim.state, True,
+                                        int(latency_ticks))
 
     def get(self, key: str, *, allow_observer: bool = True,
             wait_for_leader: bool = False) -> Tuple[int, int]:
@@ -182,49 +326,20 @@ class BWKVService:
            entry committed before the read began.
 
         Returns ``(value, revision)`` with ``revision = readindex``; the
-        round's latency (ticks from request to serve) is recorded via
-        `_record_read`."""
-        kid = self._key_id(key)
-        t0 = int(self._fetch(self.sim.state["tick"]))
-        lid = int(self._fetch(SM.leader_id(self.sim.state, self.sim.static)))
-        if lid < 0 and not wait_for_leader:
+        round's latency (ticks from request to serve) is appended to
+        `read_latencies` and folded into the read histogram."""
+        status, value, fence, latency = self._request(
+            self._get, self._key_id(key), self.session_floor, self.timeout,
+            allow_observer=allow_observer, wait_for_leader=wait_for_leader)
+        if status == NO_LEADER:
             raise NotLeader("no leader for readindex")
-        waited = 0
-        while lid < 0:
-            self._step(5)
-            waited += 5
-            if waited > self.timeout:
-                raise Timeout("read: no leader elected")
-            lid = int(self._fetch(SM.leader_id(self.sim.state,
-                                               self.sim.static)))
-        st = self.sim.state
-        role = self._fetch(st["role"])
-        alive = self._fetch(st["alive"])
-        readindex = max(int(self._fetch(st["commit_len"][lid])),
-                        self.session_floor)
-        applied = self._fetch(st["applied_len"])
-        node = None
-        if allow_observer:
-            obs = np.where((role == SM.OBSERVER) & alive &
-                           (applied >= readindex))[0]
-            if obs.size:
-                node = int(obs[0])
-        if node is None:
-            fol = np.where(((role == SM.FOLLOWER) | (role == SM.LEADER)) &
-                           alive & (applied >= readindex))[0]
-            node = int(fol[0]) if fol.size else lid
-        # apply-index wait: the serving replica must reach the fence
-        waited = 0
-        while int(self._fetch(self.sim.state["applied_len"][node])) < \
-                readindex:
-            self._step(1)
-            waited += 1
-            if waited > self.timeout:
-                raise Timeout("read: node never reached readindex")
-        value = int(self._fetch(self.sim.state["kv"][node, kid]))
-        self.session_floor = max(self.session_floor, readindex)
-        self._record_read(int(self._fetch(self.sim.state["tick"])) - t0)
-        return value, readindex
+        if status == TIMEOUT_LEADER:
+            raise Timeout("read: no leader elected")
+        if status == TIMEOUT_APPLY:
+            raise Timeout("read: node never reached readindex")
+        self.read_latencies.append(latency)
+        self.session_floor = max(self.session_floor, fence)
+        return value, fence
 
     def get_stale(self, key: str) -> Tuple[int, int]:
         """Bounded-staleness read through the digest tier (DESIGN.md §13).

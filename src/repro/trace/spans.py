@@ -39,8 +39,13 @@ SCOPES = TICK_SCOPES + (EPOCH_DIGEST, EPOCH_COMPACT)
 UNSCOPED = "unscoped"
 
 # ------------------------------------------------------------- host spans
-# BWKVService: the jitted tick dispatch (with its PRNG split), each
-# blocking device-to-host read, and each host-issued device write
+# BWKVService: the dispatch of each device program (one per request,
+# its ticks and their PRNG splits inside it, or one per `_step`), each
+# blocking device-to-host read (one per request: its answer), and each
+# host-issued device write (left only in `get_stale`'s read record).
+# Counters beside them: `host_reads`, `dispatches`, and `device_ticks`,
+# the ticks stepped inside the programs (ticks per dispatch is how much
+# of a request's wait the device runs without the host)
 KV_TICK = "kv.tick"
 KV_SYNC = "kv.sync"
 KV_WRITE = "kv.write"

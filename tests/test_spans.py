@@ -1,7 +1,8 @@
 """Profiler spans, scopes and counters of the program (DESIGN.md §14):
-the compiled fleet epoch and the KV service's tick carry every phase
-scope in their ops' `op_name` metadata, an op maps to its innermost
-scope, the service counts its blocking device-to-host reads exactly,
+the compiled fleet epoch and the KV service's put program carry every
+phase scope in their ops' `op_name` metadata, an op maps to its
+innermost scope, the service counts its blocking device-to-host reads,
+its program dispatches and the ticks stepped inside them exactly,
 the service and the fleet put their spans into a profiler trace nested
 under the caller's, and the compile clock counts a fresh compile."""
 import glob
@@ -159,26 +160,30 @@ def test_epoch_hlo_reads_this_codes_scopes(monkeypatch):
 
 
 def test_kv_tick_carries_every_tick_scope(svc):
+    """The put program steps the cluster inside its loop: every tick
+    phase keeps its scope there."""
     sim = svc.sim
-    hlo = svc._tickfn.lower(sim.state, sim.cfg_c,
-                            sim.rng).compile().as_text()
+    hlo = svc._put.lower(sim.state, sim.cfg_c, sim.rng, 0, 1,
+                         svc.timeout).compile().as_text()
     assert _op_name_scopes(hlo) == set(spans.TICK_SCOPES)
 
 
 def test_kv_service_counts_its_blocking_reads(svc):
-    """A put reads the leader, the log length and the start tick, then
-    per tick stepped the leader, its commit index and the tick: 3 + 3n.
-    A fenced get reads the tick, the leader, role, alive, commit index
-    and apply indexes, the serving node's apply index once more per tick
-    waited and once to pass, the value and the end tick: 9 + n."""
-    r0 = svc.host_reads
+    """A put and a fenced get each run one device program and read its
+    answer once, however many ticks they step; `device_ticks` counts the
+    ticks stepped inside the programs, which are the requests' latency
+    ticks here (the leader is elected, so nothing else is waited for)."""
+    r0, d0, t0 = svc.host_reads, svc.dispatches, svc.device_ticks
     put = svc.put("spans", 11)
     assert put.latency_ticks > 0
-    assert svc.host_reads - r0 == 3 + 3 * put.latency_ticks
-    r1 = svc.host_reads
+    assert (svc.host_reads - r0, svc.dispatches - d0) == (1, 1)
+    assert svc.device_ticks - t0 == put.latency_ticks
+    r1, d1 = svc.host_reads, svc.dispatches
     value, _ = svc.get("spans")
     assert value == 11
-    assert svc.host_reads - r1 == 9 + svc.read_latencies[-1]
+    assert (svc.host_reads - r1, svc.dispatches - d1) == (1, 1)
+    assert svc.device_ticks - t0 == put.latency_ticks + \
+        svc.read_latencies[-1]
 
 
 def _host_spans(trace_dir, names):
@@ -201,7 +206,7 @@ def _inside(inner, outer):
 
 
 def test_service_and_fleet_spans_land_in_a_trace(svc, fleet, tmp_path):
-    tick0, reads0 = int(svc.sim.state["tick"]), svc.host_reads
+    reads0, dispatches0 = svc.host_reads, svc.dispatches
     jax.profiler.start_trace(str(tmp_path))
     try:
         with jax.profiler.TraceAnnotation("put"):
@@ -214,9 +219,9 @@ def test_service_and_fleet_spans_land_in_a_trace(svc, fleet, tmp_path):
         jax.profiler.stop_trace()
     got = _host_spans(str(tmp_path), ("put", "get", "epoch") +
                       spans.HOST_SPANS)
-    assert len(got[spans.KV_SYNC]) == svc.host_reads - reads0
-    assert len(got[spans.KV_TICK]) == int(svc.sim.state["tick"]) - tick0
-    assert len(got[spans.KV_WRITE]) == 2          # the append, the read
+    assert len(got[spans.KV_SYNC]) == svc.host_reads - reads0 == 2
+    assert len(got[spans.KV_TICK]) == svc.dispatches - dispatches0 == 2
+    assert got[spans.KV_WRITE] == []              # both ran on the device
     kv = got[spans.KV_SYNC] + got[spans.KV_TICK] + got[spans.KV_WRITE]
     assert _inside(kv, got["put"] + got["get"])
     for name in (spans.FLEET_DISPATCH, spans.FLEET_FETCH,
